@@ -29,9 +29,10 @@ _SUBPROC_CODE = """
 import jax
 from repro.configs.base import get_arch
 from repro.core.engine import CephaloProgram
+from repro.launch.mesh import make_mesh
 from repro.roofline.analysis import parse_collectives
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for mode in ("layered", "per_microbatch"):
     prog = CephaloProgram(cfg, mesh, ell=4, m=1, seq=32, ga_mode=mode,
                           unroll=True)
@@ -57,6 +58,7 @@ def measured_collective_bytes() -> List[Dict]:
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"     # host emulation; never the chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _SUBPROC_CODE], env=env,
                           capture_output=True, text=True, timeout=1800)

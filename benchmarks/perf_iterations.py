@@ -22,6 +22,7 @@ jax init, and compile caches would pollute measurements):
 
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # compile-only; never the chip
 
 import argparse
 import json

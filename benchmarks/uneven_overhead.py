@@ -26,9 +26,10 @@ _SUBPROC = """
 import jax
 from repro.configs.base import get_arch
 from repro.core.engine import CephaloProgram
+from repro.launch.mesh import make_mesh
 from repro.roofline.analysis import parse_collectives
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for label, ratios in (("even", None),
                       ("skew", [0.3, 0.2, 0.15, 0.1, 0.1, 0.05, 0.05, 0.05])):
     prog = CephaloProgram(cfg, mesh, ratios=ratios, ell=1, m=1, seq=32,
@@ -84,6 +85,7 @@ def padding_overhead_model(unit: int = 500_000) -> List[Dict]:
 def measured_hlo_overhead() -> List[Dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"     # host emulation; never the chip
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _SUBPROC], env=env,
                           capture_output=True, text=True, timeout=1800)

@@ -79,6 +79,25 @@ def known_devices() -> List[str]:
     return sorted(_REGISTRY)
 
 
+#: ``jax.Device.device_kind`` → spec, for peaks of the chip a run is on.
+#: A v5e reports ``TPU v5 lite``; a kind not listed here is an error,
+#: never a default.
+_BY_DEVICE_KIND: Dict[str, DeviceSpec] = {
+    "TPU v4": TPU_V4,
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P,
+}
+
+
+def for_device_kind(kind: str) -> DeviceSpec:
+    """Spec of the chip whose ``jax.Device.device_kind`` is ``kind``."""
+    try:
+        return _BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise KeyError(f"no peaks known for device_kind {kind!r}; known: "
+                       f"{sorted(_BY_DEVICE_KIND)}") from None
+
+
 @dataclasses.dataclass(frozen=True)
 class Cluster:
     """A (possibly heterogeneous) collection of devices.

@@ -41,6 +41,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.engine.schedules import Schedule, get_schedule
 from repro.core.partition import Plan, RankPlan
+from repro.launch.mesh import make_mesh
 from repro.optim.adam import AdamConfig
 
 SUBSTRATES = ("shard_map", "loopback", "multiproc")
@@ -117,8 +118,8 @@ class SpmdEngine(TrainEngine):
                     f"shard_map substrate needs >= {plan.n} devices, "
                     f"have {jax.device_count()} (set "
                     f"--xla_force_host_platform_device_count or pass mesh)")
-            mesh = jax.make_mesh((plan.n,), ("data",),
-                                 devices=jax.devices()[: plan.n])
+            mesh = make_mesh((plan.n,), ("data",),
+                             devices=jax.devices()[: plan.n])
         self.mesh = mesh
         ratios = normalized_ratios(plan.state_ratios())
         self.program = CephaloProgram(

@@ -57,10 +57,10 @@ Schedules are walked entirely on the coordinator (workers only see
 unchanged across process boundaries; the cross-substrate parity test
 asserts params + Adam moments match loopback after N steps.
 
-On a real multi-node fleet the spawned workers become one JAX process
-per GPU; pass ``jax_coordinator="host:port"`` to let each worker attempt
-``jax.distributed.initialize`` (best-effort, ignored when the backend
-lacks multi-process support — e.g. this CPU container).
+Workers pin JAX to the CPU: they emulate ranks on the host, and their
+coordinator may hold the accelerator.  Pass ``jax_coordinator="host:port"``
+to have each worker call ``jax.distributed.initialize``; a failure there
+stops the worker, and the fleet fails to start.
 """
 
 from __future__ import annotations
@@ -721,18 +721,18 @@ class _Worker:
 def _worker_main(spec: WorkerSpec, conn, ring_prev=None,
                  ring_next=None) -> None:
     """Entry point of one spawned rank process."""
+    # Workers emulate ranks on the host.  Their coordinator may already
+    # hold an accelerator (a chip belongs to one process), so pin the CPU
+    # before this process touches a backend.
+    jax.config.update("jax_platforms", "cpu")
+    if spec.jax_coordinator:    # pragma: no cover - needs a multi-node fleet
+        # a failure raises: the worker dies before "ready", and the
+        # coordinator reports that rank as failed to start
+        jax.distributed.initialize(spec.jax_coordinator,
+                                   num_processes=spec.n_ranks,
+                                   process_id=spec.rank)
     channel = Channel(conn, transport=spec.transport)
     channel.send("ready", {"pid": os.getpid(), "rank": spec.rank})
-    if spec.jax_coordinator:
-        try:    # pragma: no cover - needs a multi-node jax backend
-            jax.distributed.initialize(spec.jax_coordinator,
-                                       num_processes=spec.n_ranks,
-                                       process_id=spec.rank)
-        except Exception as e:  # noqa: BLE001 - best-effort, but reported
-            warnings.warn(
-                f"rank {spec.rank}: jax.distributed.initialize"
-                f"({spec.jax_coordinator!r}) failed ({e!r}); continuing "
-                "as a single-process backend", RuntimeWarning)
     links = None
     if ring_prev is not None and ring_next is not None:
         links = _RingLinks(spec.rank, spec.n_ranks,

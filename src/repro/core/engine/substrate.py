@@ -45,15 +45,10 @@ from repro.core.engine.units import UnitGroup, UnitPlanner
 
 
 def shard_map_call(fn, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` entry (the substrate owns the SPMD
-    binding): jax >= 0.6 exposes ``jax.shard_map(check_vma=...)``, older
-    releases ``jax.experimental.shard_map.shard_map(check_rep=...)``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """The substrate's one ``shard_map`` binding (no replication check:
+    the step's outputs are per-device shards plus a psum'd loss)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class CollectiveSubstrate(abc.ABC):

@@ -57,10 +57,6 @@ class UnitLayout:
         return max(self.shard_sizes)
 
     @property
-    def even(self) -> bool:
-        return len(set(self.shard_sizes)) == 1
-
-    @property
     def n(self) -> int:
         return len(self.shard_sizes)
 
@@ -100,16 +96,18 @@ def unflatten_unit(layout: UnitLayout, flat: jax.Array,
     return jax.tree.unflatten(layout.treedef, leaves)
 
 
-def shard_unit(layout: UnitLayout, flat: jax.Array) -> List[jax.Array]:
-    """Host-side: flat (padded,) → list of (P_max,) padded shards (the SPMD
-    wire format: XLA arrays must be uniform per device)."""
-    out, off = [], 0
-    for s in layout.shard_sizes:
-        buf = jnp.zeros((layout.p_max,), flat.dtype)
-        buf = buf.at[:s].set(flat[off: off + s])
-        out.append(buf)
-        off += s
-    return out
+def shard_unit(layout: UnitLayout, flat: jax.Array,
+               rank: jax.Array) -> jax.Array:
+    """flat (padded,) → rank's (P_max,) shard in the SPMD wire format:
+    its ``shard_sizes[rank]`` values, then zeros (XLA arrays must be
+    uniform per device).  ``rank`` may be traced (``lax.axis_index``), so
+    each device of a ``shard_map`` cuts out only its own shard."""
+    offsets = jnp.asarray(layout.offsets(), jnp.int32)
+    sizes = jnp.asarray(layout.shard_sizes, jnp.int32)
+    buf = jax.lax.dynamic_slice(jnp.pad(flat, (0, layout.p_max)),
+                                (offsets[rank],), (layout.p_max,))
+    return jnp.where(jnp.arange(layout.p_max) < sizes[rank], buf,
+                     jnp.zeros_like(buf))
 
 
 def shard_unit_ragged(layout: UnitLayout, flat) -> List[np.ndarray]:
@@ -134,13 +132,12 @@ def gather_unit(layout: UnitLayout, shard: jax.Array,
                 axis_names) -> jax.Array:
     """(P_max,) local shard → (padded,) full flat buffer.  One AllGather.
 
-    Even shards take the fast path (pure reshape after gather); uneven
-    shards pay the concat-of-slices reassembly — the measured analogue of
-    the paper's generalized-collective overhead.
+    The buffer is reassembled from per-rank slices, even shards included:
+    a reshape of the gathered rows instead made the TPU compile about 9×
+    slower (266 s vs 30 s for the 24-layer stablelm-1.6b step over a
+    v5e:2x2, compiled on an 8-core host) to save one copy.
     """
     stacked = jax.lax.all_gather(shard, axis_names)      # (N, P_max)
-    if layout.even:
-        return stacked.reshape(-1)[: layout.padded]
     parts = [stacked[i, : layout.shard_sizes[i]] for i in range(layout.n)]
     return jnp.concatenate(parts)
 
@@ -178,12 +175,9 @@ def make_mixed_gather(layout: UnitLayout, axis_names, fwd_dtype,
 
 def scatter_grad(layout: UnitLayout, grad_flat: jax.Array,
                  axis_names) -> jax.Array:
-    """(padded,) full grad → (P_max,) reduced local shard.
-    One ReduceScatter (fast path) or pad+scatter for uneven shards."""
-    if layout.even:
-        return jax.lax.psum_scatter(
-            grad_flat.reshape(layout.n, layout.p_max), axis_names,
-            scatter_dimension=0, tiled=False)
+    """(padded,) full grad → (P_max,) reduced local shard: per-rank rows
+    padded to P_max, then one ReduceScatter (no reshape path for even
+    shards, for the compile time noted in :func:`gather_unit`)."""
     rows = []
     for i, off in enumerate(layout.offsets()):
         seg = grad_flat[off: off + layout.shard_sizes[i]]

@@ -43,7 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.core import fsdp
 from repro.core.engine.schedules import Schedule, get_schedule
-from repro.core.engine.substrate import ShardMapSubstrate
+from repro.core.engine.substrate import ShardMapSubstrate, shard_map_call
 from repro.core.engine.units import (UnitGroup, UnitPlanner, merge_params,
                                      split_params)
 from repro.models import model as M
@@ -123,16 +123,16 @@ class CephaloProgram:
                     shape, jnp.float32)
         return out
 
+    def _state_spec(self, name: str) -> P:
+        if name == "step":
+            return P()
+        g = self.group(name.split("/")[0])
+        return P(None, self.state_axes) if g.count > 1 \
+            else P(self.state_axes)
+
     def state_shardings(self) -> Dict[str, Any]:
-        def spec(g: UnitGroup):
-            return P(None, self.state_axes) if g.count > 1 \
-                else P(self.state_axes)
-        out = {"step": NamedSharding(self.mesh, P())}
-        for g in self.groups:
-            s = NamedSharding(self.mesh, spec(g))
-            for part in ("p", "m", "v"):
-                out[f"{g.name}/{part}"] = s
-        return out
+        return {n: NamedSharding(self.mesh, self._state_spec(n))
+                for n in self._state_names()}
 
     def batch_shapes(self) -> Dict[str, Any]:
         b = (self.n, self.ell, self.m, self.seq)
@@ -150,19 +150,32 @@ class CephaloProgram:
         s = NamedSharding(self.mesh, P(self.axes))
         return {k: s for k in self.batch_shapes()}
 
-    def _shard_group_tree(self, g: UnitGroup, tree: Any) -> jnp.ndarray:
-        """One unit group's full tree → padded shard buffer(s): a
-        (N·P_max,) vector, or a (count, N·P_max) stack for stage units."""
-        if g.count > 1:
-            flats = []
-            for i in range(g.count):
-                elem = jax.tree.map(lambda a, i=i: a[i], tree)
-                flats.append(fsdp.flatten_unit(g.layout, elem))
-            return jnp.stack(
-                [jnp.concatenate(fsdp.shard_unit(g.layout, f))
-                 for f in flats])                # (count, N*P_max)
-        flat = fsdp.flatten_unit(g.layout, tree)
-        return jnp.concatenate(fsdp.shard_unit(g.layout, flat))
+    def _layout_state(self, trees: Dict[str, Any], step: jax.Array
+                      ) -> Dict[str, jax.Array]:
+        """Full model-shaped trees (``trees["p"]`` and optionally "m"/"v",
+        replicated) → sharded state.  Runs as a ``shard_map``: each device
+        cuts only its own (P_max,) shard of every unit, so no device holds
+        the whole laid-out state.  Missing moments are zero."""
+        def local(trees, step):
+            rank = jax.lax.axis_index(self.state_axes)
+            grouped = {k: split_params(self.cfg, t) for k, t in trees.items()}
+            out = {"step": step}
+            for g in self.groups:
+                def one(elem, _g=g):
+                    flat = fsdp.flatten_unit(_g.layout, elem)
+                    return fsdp.shard_unit(_g.layout, flat, rank)
+
+                # stage units: one traced body for the whole stack
+                shard = jax.vmap(one) if g.count > 1 else one
+                for part in ("p", "m", "v"):
+                    out[f"{g.name}/{part}"] = (
+                        shard(grouped[part][g.name]) if part in grouped
+                        else jnp.zeros_like(out[f"{g.name}/p"]))
+            return out
+
+        out_specs = {n: self._state_spec(n) for n in self._state_names()}
+        return shard_map_call(local, self.mesh, (P(), P()), out_specs)(
+            trees, step)
 
     def state_from_trees(self, params: Dict[str, Any],
                          m_tree: Optional[Dict[str, Any]] = None,
@@ -173,27 +186,20 @@ class CephaloProgram:
         The import half of the elastic state-migration seam: params and
         (optionally) Adam moment trees are laid out on THIS program's
         shard layouts.  Missing moments initialize to zero."""
-        grouped_p = split_params(self.cfg, params)
-        grouped_m = split_params(self.cfg, m_tree) if m_tree is not None \
-            else None
-        grouped_v = split_params(self.cfg, v_tree) if v_tree is not None \
-            else None
-        out: Dict[str, jax.Array] = {"step": jnp.int32(step)}
-        for g in self.groups:
-            pbuf = self._shard_group_tree(g, grouped_p[g.name])
-            out[f"{g.name}/p"] = pbuf
-            out[f"{g.name}/m"] = (
-                self._shard_group_tree(g, grouped_m[g.name])
-                if grouped_m is not None else jnp.zeros_like(pbuf))
-            out[f"{g.name}/v"] = (
-                self._shard_group_tree(g, grouped_v[g.name])
-                if grouped_v is not None else jnp.zeros_like(pbuf))
-        shardings = self.state_shardings()
-        return {k: jax.device_put(v, shardings[k]) for k, v in out.items()}
+        trees = {k: t for k, t in (("p", params), ("m", m_tree),
+                                   ("v", v_tree)) if t is not None}
+        build = jax.jit(self._layout_state,
+                        out_shardings=self.state_shardings())
+        return build(trees, jnp.int32(step))
 
     def init_state(self, key: jax.Array) -> Dict[str, jax.Array]:
-        """Materialize real state (small models / examples only)."""
-        return self.state_from_trees(M.init_params(self.cfg, key))
+        """Materialize real state from ``M.init_params(cfg, key)``: the
+        draw and the layout are one ``jit``, so the state is built in
+        place, each device making only its own shards."""
+        def build(k):
+            return self._layout_state({"p": M.init_params(self.cfg, k)},
+                                      jnp.int32(0))
+        return jax.jit(build, out_shardings=self.state_shardings())(key)
 
     def gather_part(self, state: Dict[str, jax.Array],
                     part: str = "p") -> Dict[str, Any]:
@@ -442,22 +448,11 @@ class CephaloProgram:
 
     # --- public: the jitted step ------------------------------------------
     def build(self) -> Callable:
-        from repro.core.engine.substrate import shard_map_call
-
         names = self._state_names()
         bnames = self._batch_names()
-
-        def state_spec(name: str) -> P:
-            if name == "step":
-                return P()
-            gname = name.split("/")[0]
-            g = self.group(gname)
-            return P(None, self.state_axes) if g.count > 1 \
-                else P(self.state_axes)
-
-        in_specs = tuple(state_spec(n) for n in names) + \
+        in_specs = tuple(self._state_spec(n) for n in names) + \
             tuple(P(self.axes) for _ in bnames)
-        out_specs = tuple(state_spec(n) for n in names) + (P(),)
+        out_specs = tuple(self._state_spec(n) for n in names) + (P(),)
 
         def wrapped(*args):
             outs = self._device_step(*args)

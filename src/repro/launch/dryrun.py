@@ -5,7 +5,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 mesh) combination and dump memory/cost/collective analyses.
 
 The two lines above MUST stay first: jax locks the device count on first
-init, and the production meshes need 512 placeholder host devices.
+init, and the production meshes need 512 placeholder host devices.  The
+dry-run is compile-only host emulation, so it pins the CPU platform
+before any backend starts (on a TPU host it must not take the chip).
 
 Usage::
 
@@ -25,6 +27,8 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
+
+jax.config.update("jax_platforms", "cpu")
 
 from repro.configs.base import (ASSIGNED, INPUT_SHAPES, get_arch,
                                 input_specs, shape_applicable)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -63,6 +63,8 @@ from repro.core.engine.transport import TOPOLOGIES, resolve_topology
 from repro.core.model_stats import build_model_stats
 from repro.core.planner import auto_solve
 from repro.data.pipeline import DataConfig, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.optim.adam import AdamConfig
 
 CLUSTERS = {
@@ -208,7 +210,7 @@ def run_spmd(args) -> None:
     n = jax.device_count()
     shape = {1: (1, 1)}.get(n) or (
         (n // 2, 2) if n % 2 == 0 else (n, 1))
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     per_dev = max(args.batch // n, 1)
     plan = homogeneous_plan(n, ell=args.ell,
                             m=max(per_dev // args.ell, 1), device="host")
@@ -258,6 +260,7 @@ def main() -> None:
                          "(requires --elastic)")
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.runtime != "mpmd" and (args.elastic or args.straggler):
         raise SystemExit("--elastic/--straggler require --runtime mpmd "
                          "(the replanning loop drives the planner, which "

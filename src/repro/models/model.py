@@ -102,9 +102,10 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict[str, Any]:
         params["shared"] = B.dense_block_init(next(keys), cfg, local=False)
     stages = []
     for spec in build_stages(cfg):
-        elems = [_element_init(k, cfg, spec)
-                 for k in jax.random.split(next(keys), spec.count)]
-        stages.append(_stack(elems))
+        # vmap over the element keys: the same draws as one call per
+        # element, in one traced body however deep the stage is
+        stages.append(jax.vmap(lambda k, s=spec: _element_init(k, cfg, s))(
+            jax.random.split(next(keys), spec.count)))
     params["stages"] = stages
     return params
 

@@ -21,9 +21,10 @@ from repro.core.layered_ga import CephaloProgram
 from repro.models import model as M
 from repro.optim.adam import AdamConfig, adam_init, adam_update
 from repro.data.pipeline import SyntheticStream, DataConfig, make_homogeneous_batch
+from repro.launch.mesh import make_mesh
 
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 N, ell, m, seq = 8, 2, 2, 32
 B = N * ell * m
 stream = SyntheticStream(DataConfig(vocab_size=cfg.vocab_size, seq_len=seq, seed=0))
@@ -73,11 +74,12 @@ def test_layered_ga_reduces_collective_traffic(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
 from repro.configs.base import get_arch
+from repro.launch.mesh import make_mesh
 from repro.core.layered_ga import CephaloProgram
 from repro.roofline.analysis import parse_collectives
 
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ell = 4
 
 def coll(mode):
@@ -112,10 +114,11 @@ def test_sharded_decode_matches_unsharded(subproc):
 import jax, jax.numpy as jnp
 from repro.configs.base import get_arch, InputShape
 from repro.launch import serving
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, S = 4, 64
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
@@ -230,13 +233,14 @@ def test_hsdp_state_axes_matches_reference(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp
 from repro.configs.base import get_arch
+from repro.launch.mesh import make_mesh
 from repro.core.layered_ga import CephaloProgram
 from repro.models import model as M
 from repro.optim.adam import AdamConfig, adam_init, adam_update
 from repro.data.pipeline import SyntheticStream, DataConfig, make_homogeneous_batch
 
 cfg = get_arch("stablelm-1.6b").reduced()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 N, ell, m, seq = 8, 1, 2, 32
 B = N * ell * m
 stream = SyntheticStream(DataConfig(cfg.vocab_size, seq, seed=0))

@@ -41,10 +41,11 @@ def test_shard_concat_identity():
     assert [len(s) for s in shards] == layout.shard_sizes
     np.testing.assert_allclose(np.concatenate(shards), np.asarray(flat))
     # padded SPMD wire format: valid prefixes match
-    padded = fsdp.shard_unit(layout, flat)
-    for p, r in zip(padded, shards):
+    for i, r in enumerate(shards):
+        p = fsdp.shard_unit(layout, flat, jnp.int32(i))
         np.testing.assert_allclose(np.asarray(p[: len(r)]), r)
         assert p.shape == (layout.p_max,)
+        assert not np.any(np.asarray(p[len(r):]))
 
 
 @given(n=st.integers(1, 32), seed=st.integers(0, 100),

@@ -2,7 +2,6 @@
 runtime (the paper's full pipeline: profile → optimize → train)."""
 
 import jax
-import numpy as np
 
 from repro.configs.base import get_arch
 from repro.core import device_specs as D
@@ -40,14 +39,6 @@ def test_serving_sharding_rules_cover_all_archs():
     from repro.configs.base import ASSIGNED
     from repro.launch import serving
     from repro.models import model as M
-
-    class FakeMesh:
-        axis_names = ("data", "model")
-        shape = {"data": 16, "model": 16}
-        devices = np.zeros((16, 16))
-
-    import jax.sharding as jsh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
 
     for arch in ASSIGNED:
         cfg = get_arch(arch)
