@@ -1,0 +1,12 @@
+"""Device time per step in ops of the program's ``attention`` scope
+(``attention_apply``: the q, k, v and output projections, rotary and the
+attention itself), in the forward, the backward and the recomputed
+forward alike, averaged over the devices.  None where no op carries the
+scope."""
+
+import program_trace
+
+
+def read(r, facts):
+    t = program_trace.load()
+    return t.scope_ms("attention") if t else None

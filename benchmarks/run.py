@@ -44,8 +44,7 @@ def main() -> None:
                          "(written unless --fast; '' disables)")
     args = ap.parse_args()
 
-    from benchmarks import (elastic_recovery, grad_accum, model_accuracy,
-                            roofline_table)
+    from benchmarks import elastic_recovery, grad_accum, model_accuracy
     from benchmarks import tables as T
     from benchmarks import uneven_overhead
 
@@ -96,9 +95,6 @@ def main() -> None:
             ("appc_measured_hlo", uneven_overhead.measured_hlo_overhead,
              lambda rows: f"overhead={rows[-1].get('allgather_bytes', '?')}"),
         ]
-    sections.append(
-        ("roofline_table", lambda: roofline_table.rows("pod16x16"),
-         lambda rows: f"ok={sum(1 for r in rows if r['status'] == 'ok')}/40"))
 
     csv_lines = ["name,us_per_call,derived"]
     details = []

@@ -37,6 +37,7 @@ from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.engine.schedules import Schedule, get_schedule
@@ -127,19 +128,37 @@ class SpmdEngine(TrainEngine):
             m=max(plan.m_pad, 1), seq=seq_len, schedule=schedule,
             adam=adam, **knobs)
         self._jitted = None
+        self._steps = 0
 
     def init_state(self, key: jax.Array) -> Dict[str, jax.Array]:
         return self.program.init_state(key)
 
     def step(self, state, big: np.ndarray):
+        """One step, under profiler spans on the trace's own clock:
+        ``spmd.step`` around the call, and inside it ``spmd.grid`` (the
+        padded grid, with its real and padding rows), ``spmd.put`` (the
+        transfer, with its bytes), ``spmd.dispatch`` (the enqueue of the
+        jitted step) and ``spmd.loss_wait`` (the host's wait for the
+        loss)."""
         from repro.data.pipeline import plan_grid_from_block
         import jax.numpy as jnp
-        if self._jitted is None:
-            self._jitted = self.program.jit_step()
-        grid = plan_grid_from_block(self.plan, np.asarray(big))
-        batch = {k: jnp.asarray(v) for k, v in grid.items()}
-        new_state, loss = self._jitted(state, batch)
-        return new_state, float(loss)
+        plan = self.plan
+        rows = plan.n * max(plan.ell_pad, 1) * max(plan.m_pad, 1)
+        with StepTraceAnnotation("spmd.step", step_num=self._steps):
+            self._steps += 1
+            if self._jitted is None:
+                self._jitted = self.program.jit_step()
+            with TraceAnnotation("spmd.grid", rows_real=plan.global_batch,
+                                 rows_padded=rows - plan.global_batch):
+                grid = plan_grid_from_block(plan, np.asarray(big))
+            with TraceAnnotation("spmd.put", bytes=sum(
+                    v.nbytes for v in grid.values())):
+                batch = {k: jnp.asarray(v) for k, v in grid.items()}
+            with TraceAnnotation("spmd.dispatch"):
+                new_state, loss = self._jitted(state, batch)
+            with TraceAnnotation("spmd.loss_wait"):
+                loss = float(loss)
+        return new_state, loss
 
     def gather_params(self, state) -> Dict[str, Any]:
         return self.program.gather_params(state)

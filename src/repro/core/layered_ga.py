@@ -422,16 +422,17 @@ class CephaloProgram:
 
         # Adam on local shards (ZeRO-3: fully local update)
         new_state = {"step": state["step"] + 1}
-        for g in self.groups:
-            p = state[f"{g.name}/p"]
-            gm = state[f"{g.name}/m"]
-            gv = state[f"{g.name}/v"]
-            gr = grads[g.name].astype(jnp.float32)
-            np_, nm, nv = adam_update(self.adam, p, gr, gm, gv,
-                                      state["step"] + 1)
-            new_state[f"{g.name}/p"] = np_
-            new_state[f"{g.name}/m"] = nm
-            new_state[f"{g.name}/v"] = nv
+        with jax.named_scope("adam"):
+            for g in self.groups:
+                p = state[f"{g.name}/p"]
+                gm = state[f"{g.name}/m"]
+                gv = state[f"{g.name}/v"]
+                gr = grads[g.name].astype(jnp.float32)
+                np_, nm, nv = adam_update(self.adam, p, gr, gm, gv,
+                                          state["step"] + 1)
+                new_state[f"{g.name}/p"] = np_
+                new_state[f"{g.name}/m"] = nm
+                new_state[f"{g.name}/v"] = nv
         return tuple(new_state[k] for k in names) + (loss,)
 
     def _state_names(self) -> List[str]:
@@ -475,6 +476,12 @@ class CephaloProgram:
         return step
 
     def jit_step(self) -> Callable:
+        # Profiler traces attribute device time by the op metadata (the
+        # named scopes), which JAX's persistent compilation cache leaves
+        # out of its key by default: an executable cached from the same
+        # step with other scopes would be reused, stale names and all.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         step = self.build()
         state_sh = self.state_shardings()
         batch_sh = self.batch_shardings()

@@ -86,6 +86,7 @@ def dense_block_init(key: jax.Array, cfg: ArchConfig,
     return p
 
 
+@jax.named_scope("mlp")
 def _ffn(params: dict, x: jax.Array, cfg: ArchConfig,
          dropless: bool = False):
     if cfg.is_moe:

@@ -262,6 +262,7 @@ def _pallas_attention(q, k, v, spec: AttnSpec, interpret: bool):
     return out.swapaxes(1, 2)
 
 
+@jax.named_scope("attention")
 def attention_apply(params: dict, x: jax.Array, spec: AttnSpec,
                     positions: jax.Array,
                     kv_override: Optional[Tuple[jax.Array, jax.Array,

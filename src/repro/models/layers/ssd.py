@@ -194,6 +194,7 @@ def _causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
     return jax.nn.silu(out), new_state
 
 
+@jax.named_scope("ssd")
 def ssd_apply(params: dict, x: jax.Array, spec: SSMSpec,
               h0: Optional[jax.Array] = None,
               conv0: Optional[jax.Array] = None,

@@ -240,6 +240,7 @@ def forward_hidden(cfg: ArchConfig, params: Dict[str, Any],
 # Loss (chunked cross-entropy)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ce")
 def chunked_ce(cfg: ArchConfig, params: Dict[str, Any], h: jax.Array,
                labels: jax.Array, weights: jax.Array,
                chunk: int = 512) -> jax.Array:
