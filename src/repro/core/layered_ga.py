@@ -15,8 +15,11 @@ execution engine (:mod:`repro.core.engine`, DESIGN.md §Engine):
   baseline, Fig. 4 top — every microbatch pays the full per-unit
   collective bill), ``interleaved``, or any registered schedule.  The
   layered schedule falls out of the loop structure (unit loop outer,
-  microbatch scan inner) plus full rematerialization (the bwd re-gathers
-  instead of saving gathered params).
+  microbatch scan inner) plus full rematerialization, which has two
+  levels: the unit (the bwd re-gathers instead of saving gathered
+  params) and, inside it, the microbatch (the bwd recomputes and
+  transposes one microbatch at a time, so one microbatch's residuals
+  live at a time, never a stack of ℓ).
 * **ShardMapSubstrate** provides the differentiable mixed-precision
   gather whose VJP is the per-unit ReduceScatter (plus the HSDP replica
   all-reduce).
@@ -308,6 +311,12 @@ class CephaloProgram:
                     y, a = M.element_apply(cfg, _spec, w_tree, x_mb,
                                            positions, shared_tree)
                     return None, (y, a)
+
+                if self.remat == "full":
+                    # nested remat: without it the bwd stacks every
+                    # microbatch's residuals over ell (PERF.md §5); w_tree
+                    # stays closed over, so the unit is gathered once.
+                    mb_body = jax.checkpoint(mb_body)
 
                 _, (ys, auxs) = jax.lax.scan(mb_body, None, x_all)
                 return (ys, aux + jnp.sum(auxs)), None

@@ -13,8 +13,10 @@ import pytest
 
 
 @pytest.mark.integration
-def test_spmd_step_matches_reference(subproc):
-    out = subproc("""
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "mamba2-370m"],
+                         ids=["dense", "ssm"])
+def test_spmd_step_matches_reference(subproc, arch):
+    out = subproc(f"ARCH = {arch!r}\n" + """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_arch
 from repro.core.layered_ga import CephaloProgram
@@ -23,7 +25,7 @@ from repro.optim.adam import AdamConfig, adam_init, adam_update
 from repro.data.pipeline import SyntheticStream, DataConfig, make_homogeneous_batch
 from repro.launch.mesh import make_mesh
 
-cfg = get_arch("stablelm-1.6b").reduced()
+cfg = get_arch(ARCH).reduced()
 mesh = make_mesh((2, 4), ("data", "model"))
 N, ell, m, seq = 8, 2, 2, 32
 B = N * ell * m
