@@ -4,7 +4,9 @@ devices.  None where no op carries the scope."""
 
 import program_trace
 
+SCOPE = "adam"
+
 
 def read(r, facts):
-    t = program_trace.load()
-    return t.scope_ms("adam") if t else None
+    t = program_trace.load(scopes=facts["scopes"])
+    return t.scope_ms(SCOPE) if t else None

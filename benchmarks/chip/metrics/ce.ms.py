@@ -5,7 +5,9 @@ over the devices.  None where no op carries the scope."""
 
 import program_trace
 
+SCOPE = "ce"
+
 
 def read(r, facts):
-    t = program_trace.load()
-    return t.scope_ms("ce") if t else None
+    t = program_trace.load(scopes=facts["scopes"])
+    return t.scope_ms(SCOPE) if t else None

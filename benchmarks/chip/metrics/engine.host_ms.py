@@ -7,5 +7,5 @@ import program_trace
 
 
 def read(r, facts):
-    t = program_trace.load()
+    t = program_trace.load(scopes=facts["scopes"])
     return t.host_ms() if t else None

@@ -4,7 +4,9 @@ alike, averaged over the devices.  None where no op carries the scope."""
 
 import program_trace
 
+SCOPE = "mlp"
+
 
 def read(r, facts):
-    t = program_trace.load()
-    return t.scope_ms("mlp") if t else None
+    t = program_trace.load(scopes=facts["scopes"])
+    return t.scope_ms(SCOPE) if t else None

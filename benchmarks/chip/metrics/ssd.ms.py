@@ -6,7 +6,9 @@ scope."""
 
 import program_trace
 
+SCOPE = "ssd"
+
 
 def read(r, facts):
-    t = program_trace.load()
-    return t.scope_ms("ssd") if t else None
+    t = program_trace.load(scopes=facts["scopes"])
+    return t.scope_ms(SCOPE) if t else None

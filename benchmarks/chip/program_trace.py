@@ -6,8 +6,8 @@ opcode.  This module reads what the program writes into the same trace:
 * host spans of ``SpmdEngine.step`` (:data:`SPANS`), from the host planes:
   ``spmd.step`` around each call, and inside it ``spmd.grid``,
   ``spmd.put``, ``spmd.dispatch`` and ``spmd.loss_wait``;
-* the ``jax.named_scope`` of every device op (:data:`SCOPES`), from the
-  op's JAX ``op_name``.  On a TPU v5e the events of a ``/device:TPU:<i>``
+* the ``jax.named_scope`` of every device op, from the op's JAX
+  ``op_name``.  On a TPU v5e the events of a ``/device:TPU:<i>``
   plane's "XLA Ops" line carry the instruction's HLO text and no
   ``op_name`` stat (their stats are ``device_offset_ps``,
   ``device_duration_ps`` and ``Time Scale Multiplier``), so the
@@ -16,13 +16,16 @@ opcode.  This module reads what the program writes into the same trace:
   a small protobuf decoder, since ``ProfileData`` shows no event
   metadata.
 
-An op's scope is the innermost of :data:`SCOPES` that is a component of
-its ``op_name`` once wrappers such as ``transpose(...)`` and ``jvp(...)``
-are stripped, so a backward op (``transpose(jvp(attention))``) and a
-rematerialized forward (``.../checkpoint/attention/...``) count with the
-forward's scope.  An op with none of them is unscoped.  Containers
-(``while``, ``conditional``, ``call``) are left out, as ``trace_reduce``
-leaves them out of its class times.
+The scopes a cell splits its ops by are the ``SCOPE`` names of the
+per-layer readers listed for that cell (``registry.scopes``), so a scope
+added for one cell cannot move a reading in another.  An op's scope is the
+innermost of that set that is a component of its ``op_name`` once
+wrappers such as ``transpose(...)`` and ``jvp(...)`` are stripped, so a
+backward op (``transpose(jvp(attention))``) and a rematerialized forward
+(``.../checkpoint/attention/...``) count with the forward's scope.  An op
+with none of them is unscoped.  Containers (``while``, ``conditional``,
+``call``) are left out, as ``trace_reduce`` leaves them out of its class
+times.
 
 Both are clipped to the window ``trace_reduce`` uses: from the start of
 the first ``engine.step`` span to the end of the last ``sync`` span.
@@ -38,7 +41,8 @@ import dataclasses
 import glob
 import os
 import re
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import (Collection, Dict, FrozenSet, List, Optional, Sequence,
+                    Tuple)
 
 import trace_reduce as T
 
@@ -51,9 +55,6 @@ SPANS = ("spmd.step", "spmd.grid", "spmd.put", "spmd.dispatch",
          "spmd.loss_wait")
 #: the spans of the host's own work in a step (the loss wait left out)
 HOST_WORK = ("spmd.grid", "spmd.put", "spmd.dispatch")
-#: the program's named scopes
-SCOPES = ("attention", "mlp", "ssd", "ce", "adam")
-
 _CONTAINERS = ("while", "conditional", "call")
 _WRAPPED = re.compile(r"^[\w.\-]+\((.*)\)$")
 
@@ -84,12 +85,12 @@ def _unwrap(component: str) -> str:
     return component
 
 
-def scope_of(op_name: str) -> Optional[str]:
-    """The innermost of :data:`SCOPES` in ``op_name``, or None."""
+def scope_of(op_name: str, scopes: Collection[str]) -> Optional[str]:
+    """The innermost of ``scopes`` in ``op_name``, or None."""
     for comp in reversed(_components(op_name or "")):
         inner = _unwrap(comp)
         for part in reversed(_components(inner)):
-            if part in SCOPES:
+            if part in scopes:
                 return part
     return None
 
@@ -105,8 +106,9 @@ def is_container(name: str) -> bool:
 class Events:
     """What the trace holds for this module, on the profiler's clock (ns)."""
 
-    #: one list per device: (scope or None, start, end), containers left out
-    ops: List[List[Tuple[Optional[str], int, int]]]
+    #: one list per device: (op_name, "" where none; start, end),
+    #: containers left out
+    ops: List[List[Tuple[str, int, int]]]
     #: per device, every op's interval, containers included (busy time)
     busy: List[List[Interval]]
     #: (span name, start, end): the program's and the benchmark's spans
@@ -174,8 +176,9 @@ class ProgramTrace:
         return self.idle_ms_under(*HOST_WORK)
 
 
-def reduce(ev: Events) -> ProgramTrace:
-    """Clip ``ev`` to the benchmark's window and sum it."""
+def reduce(ev: Events, scopes: Collection[str]) -> ProgramTrace:
+    """Clip ``ev`` to the benchmark's window and sum it, each op under
+    its innermost scope of ``scopes``."""
     steps = [s for s in ev.spans if s[0] == "engine.step"]
     syncs = [s for s in ev.spans if s[0] == "sync"]
     if not steps or not syncs:
@@ -187,11 +190,14 @@ def reduce(ev: Events) -> ProgramTrace:
         return [(max(s, t0), min(e, t1)) for s, e in iv if e > t0 and s < t1]
 
     scope_ns = []
+    scope: Dict[str, Optional[str]] = {}      # per distinct op_name
     for ops in ev.ops:
         per: Dict[Optional[str], int] = {}
-        for scope, s, e in ops:
+        for name, s, e in ops:
+            if name not in scope:
+                scope[name] = scope_of(name, scopes)
             for a, b in clip([(s, e)]):
-                per[scope] = per.get(scope, 0) + b - a
+                per[scope[name]] = per.get(scope[name], 0) + b - a
         scope_ns.append(per)
     spans: Dict[str, List[Interval]] = {}
     for name, s, e in ev.spans:
@@ -203,22 +209,22 @@ def reduce(ev: Events) -> ProgramTrace:
 
 
 def device_ops(events, op_names: Dict[str, str]
-               ) -> Tuple[List[Tuple[Optional[str], int, int]],
-                          List[Interval]]:
+               ) -> Tuple[List[Tuple[str, int, int]], List[Interval]]:
     """One device's "XLA Ops" events, as (name, start, end), to its ops
-    by scope (containers left out) and the intervals of all.  An event's
-    name is its HLO instruction (``%fusion.1 = bf16[..] fusion(..)``);
-    ``op_names`` maps instruction names to their JAX ``op_name``."""
+    by ``op_name`` (containers left out) and the intervals of all.  An
+    event's name is its HLO instruction (``%fusion.1 = bf16[..]
+    fusion(..)``); ``op_names`` maps instruction names to their JAX
+    ``op_name``."""
     ops, busy = [], []
-    kinds: Dict[str, Tuple[bool, Optional[str]]] = {}  # per distinct event
+    kinds: Dict[str, Tuple[bool, str]] = {}  # per distinct event
     for name, s, e in events:
         busy.append((s, e))
         if name not in kinds:
-            kinds[name] = (is_container(name), scope_of(
-                op_names.get(T.instruction_name(name), "")))
-        container, scope = kinds[name]
+            kinds[name] = (is_container(name),
+                           op_names.get(T.instruction_name(name), ""))
+        container, op_name = kinds[name]
         if not container:
-            ops.append((scope, s, e))
+            ops.append((op_name, s, e))
     return ops, busy
 
 
@@ -291,33 +297,40 @@ def hlo_op_names(xspace: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
-def events_from_xspace(path: str) -> Events:
-    """Read the ``.xplane.pb`` under ``path`` (a profiler log dir)."""
+def json_form(xspace: bytes) -> Dict:
+    """A serialized ``XSpace`` in the JSON form of
+    :func:`events_from_json`: per device, its "XLA Ops" events as [name,
+    start, end]; the ``op_name`` of each instruction that ran; the host
+    spans of :data:`SPANS` and of the benchmark as [name, start, end]."""
     import jax
-    files = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not files:
-        raise FileNotFoundError(f"no .xplane.pb under {path}")
-    with open(files[-1], "rb") as fh:
-        xspace = fh.read()
     data = jax.profiler.ProfileData.from_serialized_xspace(xspace)
     devices, spans = [], []
     names = set(SPANS) | set(T.SPANS)
     for plane in data.planes:
         if re.fullmatch(r"/device:TPU:\d+", plane.name):
-            raw = [(e.name, int(e.start_ns), int(e.end_ns))
+            raw = [[e.name, int(e.start_ns), int(e.end_ns)]
                    for line in plane.lines if line.name == "XLA Ops"
                    for e in line.events]
             devices.append((int(plane.name.rsplit(":", 1)[1]), raw))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans += [(e.name, int(e.start_ns), int(e.end_ns))
+                spans += [[e.name, int(e.start_ns), int(e.end_ns)]
                           for e in line.events if e.name in names]
     devices.sort(key=lambda d: d[0])
-    op_names = merged_op_names(hlo_op_names(xspace),
-                               [raw for _, raw in devices])
-    per = [device_ops(raw, op_names) for _, raw in devices]
-    return Events([p[0] for p in per], [p[1] for p in per], spans)
+    raw = [r for _, r in devices]
+    return {"devices": raw,
+            "op_names": merged_op_names(hlo_op_names(xspace), raw),
+            "spans": spans}
+
+
+def events_from_xspace(path: str) -> Events:
+    """Read the ``.xplane.pb`` under ``path`` (a profiler log dir)."""
+    files = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {path}")
+    with open(files[-1], "rb") as fh:
+        return events_from_json(json_form(fh.read()))
 
 
 def merged_op_names(modules: Dict[str, Dict[str, str]], devices
@@ -331,24 +344,26 @@ def merged_op_names(modules: Dict[str, Dict[str, str]], devices
     for names in sorted(modules.values(),
                         key=lambda m: -len(ran.intersection(m))):
         for k, v in names.items():
-            out.setdefault(k, v)
+            if k in ran:
+                out.setdefault(k, v)
     return out
 
 
-_CACHE: Dict[Tuple[str, float], ProgramTrace] = {}
+_CACHE: Dict[Tuple[str, float, FrozenSet[str]], ProgramTrace] = {}
 
 
-def load(path: str = TRACE_DIR) -> Optional[ProgramTrace]:
-    """The trace under ``path``, read once per process and file; None
-    where there is no trace."""
+def load(path: str = TRACE_DIR, scopes: Collection[str] = ()
+         ) -> Optional[ProgramTrace]:
+    """The trace under ``path`` split by ``scopes``, read once per
+    process, file and scope set; None where there is no trace."""
     files = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
                              recursive=True))
     if not files:
         return None
-    key = (files[-1], os.path.getmtime(files[-1]))
+    key = (files[-1], os.path.getmtime(files[-1]), frozenset(scopes))
     if key not in _CACHE:
         _CACHE.clear()
-        _CACHE[key] = reduce(events_from_xspace(path))
+        _CACHE[key] = reduce(events_from_xspace(path), scopes)
     return _CACHE[key]
 
 

@@ -37,6 +37,18 @@ def _dims(cfg: Dict[str, Any]):
     return d, h, d // h, cfg["intermediate_size"], cfg["num_hidden_layers"]
 
 
+def program_sizes(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The program's ``ArchConfig`` fields that the configuration fixes,
+    beside depth, width and vocabulary: multi-head attention with heads of
+    hidden_size / num_attention_heads."""
+    return {"d_ff": cfg["intermediate_size"],
+            "n_heads": cfg["num_attention_heads"],
+            "n_kv_heads": cfg["num_key_value_heads"],
+            "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
+            "rope_theta": cfg["rope_theta"], "norm_eps": cfg["rms_norm_eps"],
+            "tie_embeddings": cfg["tie_word_embeddings"]}
+
+
 def init_params(cfg: Dict[str, Any], key: jax.Array) -> Dict[str, Any]:
     """Weights from ``key``: embeddings N(0, 1), projections truncated
     normal with std 1/sqrt(fan in), norm weights 0 (a scale of 1)."""

@@ -37,6 +37,17 @@ def _dims(cfg: Dict[str, Any]):
     return d, di, n, p, di // p, cfg["conv_kernel"], cfg["num_hidden_layers"]
 
 
+def program_sizes(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The program's ``ArchConfig`` fields that the configuration fixes,
+    beside depth, width and vocabulary."""
+    return {"ssm_state": cfg["state_size"], "ssm_expand": cfg["expand"],
+            "ssm_head_dim": cfg["head_dim"],
+            "ssm_chunk": cfg["chunk_size"],
+            "ssm_conv_width": cfg["conv_kernel"],
+            "norm_eps": cfg["rms_norm_eps"],
+            "tie_embeddings": cfg["tie_embeddings"]}
+
+
 def init_params(cfg: Dict[str, Any], key: jax.Array) -> Dict[str, Any]:
     """Weights from ``key``: projections truncated normal with std
     1/sqrt(fan in), dt_bias uniform in [-4, -1], a_log = log(1..16) over

@@ -12,13 +12,17 @@ directory, named after its entry:
   generator's parameters and the optimizer;
 * ``limits/<cell>.json``      the limits of ``correct``, with the readings
   they were set from;
-* ``metrics/<metric>.py``     one reader per per-layer metric;
-* ``flops/<family>.py`` and ``reference/<family>.py`` per architecture
-  family;
+* ``metrics/<metric>.py``     one reader per per-layer metric; a reader of
+  a named scope declares it as ``SCOPE``, and a cell's ops are split by
+  the scopes of its own readers (:func:`scopes`);
+* ``flops/<family>.py``       FLOPs per token by part, and optionally
+  bytes per token by part, of an architecture family;
+* ``reference/<family>.py``   the family's plain reference, and the sizes
+  the configuration fixes in the program (``program_sizes``);
 * ``peaks.json``              peaks keyed by ``device_kind``.
 
-A new cell, configuration or metric is new files and new entries, with
-no edit to a file that is here.
+A new cell, configuration, family, scope or metric is new files and new
+entries, with no edit to a file that is here.
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ import importlib.util
 import json
 import os
 import sys
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Tuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -88,6 +92,13 @@ def cell(name: str, bench: Dict[str, Any] = None,
         "reference": load_module(os.path.join(here, "reference",
                                               family + ".py")),
     }
+
+
+def scopes(readers: Dict[str, Any]) -> Tuple[str, ...]:
+    """The named scopes a cell splits its ops by: the ``SCOPE`` of each
+    of its readers that declares one."""
+    return tuple(sorted({r.SCOPE for r in readers.values()
+                         if hasattr(r, "SCOPE")}))
 
 
 def peaks(device_kind: str, here: str = HERE) -> Dict[str, Any]:

@@ -64,30 +64,18 @@ import traffic  # noqa: E402
 # Building the program from the cell's files
 # ---------------------------------------------------------------------------
 
-def program_config(cfg: Dict[str, Any]):
+def program_config(cfg: Dict[str, Any], reference):
     """The repository's ``ArchConfig`` for a configuration file: the
     registered architecture with the file's ``program`` overrides, checked
-    against the file's sizes."""
+    against the file's sizes: depth, width and vocabulary, and the
+    family's own (``reference.program_sizes``)."""
     import dataclasses
     from repro.configs.base import get_arch
     arch = dataclasses.replace(get_arch(cfg["arch"]), **cfg["program"])
     want = {"d_model": cfg["hidden_size"],
             "n_layers": cfg["num_hidden_layers"],
-            "vocab_size": cfg["vocab_size"]}
-    if cfg["family"] == "dense":
-        want.update(d_ff=cfg["intermediate_size"],
-                    n_heads=cfg["num_attention_heads"],
-                    n_kv_heads=cfg["num_key_value_heads"],
-                    head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
-                    rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-                    tie_embeddings=cfg["tie_word_embeddings"])
-    else:
-        want.update(ssm_state=cfg["state_size"], ssm_expand=cfg["expand"],
-                    ssm_head_dim=cfg["head_dim"],
-                    ssm_chunk=cfg["chunk_size"],
-                    ssm_conv_width=cfg["conv_kernel"],
-                    norm_eps=cfg["rms_norm_eps"],
-                    tie_embeddings=cfg["tie_embeddings"])
+            "vocab_size": cfg["vocab_size"],
+            **reference.program_sizes(cfg)}
     got = {k: getattr(arch, k) for k in want}
     if got != want:
         raise ValueError(f"{cfg['arch']} as registered does not match "
@@ -102,7 +90,7 @@ def build(spec: Dict[str, Any], devices: List[Any]):
     from repro.launch.mesh import make_mesh
     from repro.optim.adam import AdamConfig
     mix = spec["traffic"]
-    arch = program_config(spec["config"])
+    arch = program_config(spec["config"], spec["reference"])
     ranks = [RankPlan(i, f"chip{i}", m=r["m"], ell=r["ell"],
                       state_ratio=r["state_ratio"])
              for i, r in enumerate(mix["ranks"])]
@@ -310,6 +298,30 @@ class CompileCounter:
             self.count += 1
 
 
+def reader_facts(spec: Dict[str, Any], peaks: Dict[str, Any]
+                 ) -> Dict[str, Any]:
+    """What the per-layer readers get beside the reduced trace: the model's
+    FLOPs a step, in all and by part (``flops/<family>.py``
+    ``flops_per_token`` times the tokens of a step), its bytes a step by
+    part (``bytes_per_token``, where the family's file has it), the
+    device's bf16 and HBM peaks, and the named scopes the cell's readers
+    split its ops by."""
+    mix = spec["traffic"]
+    tokens = traffic.global_batch(mix) * mix["seq"]
+    fam, cfg = spec["flops"], spec["config"]
+    flops = fam.flops_per_token(cfg, mix["seq"])
+    nbytes = (fam.bytes_per_token(cfg, mix["seq"])
+              if hasattr(fam, "bytes_per_token") else {})
+    return {"flops_per_step": flops["total"] * tokens,
+            "peak_flops_per_s": peaks["bf16_flops_per_s"],
+            "flops_parts_per_step": {k: v * tokens for k, v in flops.items()
+                                     if k != "total"},
+            "bytes_parts_per_step": {k: v * tokens for k, v in nbytes.items()
+                                     if k != "total"},
+            "peak_hbm_bytes_per_s": peaks["hbm_bytes_per_s"],
+            "scopes": registry.scopes(spec["readers"])}
+
+
 def device_facts(devices) -> Dict[str, Any]:
     import jax
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -388,8 +400,7 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, trace: bool,
         classes = T.op_classes(mem["hlo"])
         labels = T.op_labels(mem["hlo"])
         red = T.reduce(T.events_from_xspace(TRACE_DIR), classes)
-        facts = {"flops_per_step": flops["total"] * tokens_per_step,
-                 "peak_flops_per_s": peaks["bf16_flops_per_s"]}
+        facts = reader_facts(spec, peaks)
         for m in spec["per_layer"]:
             v = spec["readers"][m["name"]].read(red, facts)
             if v is not None:

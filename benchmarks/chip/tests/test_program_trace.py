@@ -1,8 +1,10 @@
 """The program's spans and scopes in a trace, to the per-layer metrics
-that read them: on hand-made events, and on an excerpt of a trace
-recorded on a TPU v5e."""
+that read them: on hand-made events, and on traces recorded on a TPU v5e
+(an excerpt of a stablelm step and a whole mamba2 step), each split by its
+cell's own scope set."""
 
 import json
+import lzma
 import os
 
 import pytest
@@ -14,6 +16,9 @@ import program_trace as P
 FIXTURE = os.path.join(tiny.HERE, "fixtures")
 READERS = ("engine.host_ms", "engine.exposed_host_ms", "attention.ms",
            "mlp.ms", "ssd.ms", "ce.ms", "adam.ms", "unscoped.ms")
+#: every scope a reader names: a cell's split by its own set has to equal
+#: the split by all of them
+ALL = ("attention", "mlp", "ssd", "ce", "adam")
 
 
 def _reader(name):
@@ -48,18 +53,42 @@ NAMES = ["jit(step)/jvp()/while/body/closed_call/attention/dot_general",
          "jit(step)/while/body/dynamic_update_slice"]
 
 
+def _cell_scopes(cell):
+    return registry.scopes(registry.cell(cell)["readers"])
+
+
 def test_scope_of_strips_transformations():
-    assert [P.scope_of(n) for n in NAMES] == ["attention", "attention",
-                                              "ce", None]
-    assert P.scope_of("jit(step)/adam/sub") == "adam"
-    assert P.scope_of("jit(step)/jvp(jit(mlp_apply))/dot_general") is None
-    assert P.scope_of("") is None
-    # the innermost scope wins
-    assert P.scope_of("jit(step)/ce/x/attention/y") == "attention"
+    assert [P.scope_of(n, ALL) for n in NAMES] == ["attention", "attention",
+                                                   "ce", None]
+    assert P.scope_of("jit(step)/adam/sub", ALL) == "adam"
+    assert P.scope_of("jit(step)/jvp(jit(mlp_apply))/dot_general",
+                      ALL) is None
+    assert P.scope_of("", ALL) is None
+    # the innermost scope of the set wins
+    assert P.scope_of("jit(step)/ce/x/attention/y", ALL) == "attention"
+    assert P.scope_of("jit(step)/ce/x/attention/y", ("ce",)) == "ce"
+    assert P.scope_of("jit(step)/ce/x/attention/y", ()) is None
+
+
+def test_nested_scope_takes_ops_only_where_listed():
+    """A scope nested inside ``mlp`` takes its ops away from ``mlp`` in a
+    set that lists it, and in no other."""
+    names = ["jit(step)/mlp/router/dot_general",
+             "jit(step)/transpose(jvp(mlp))/router/mul",
+             "jit(step)/mlp/mul", "jit(step)/add"]
+    ev = _ev(names)
+    plain = P.reduce(ev, ("mlp",))
+    assert plain.scope_ms("mlp") == pytest.approx(60e-6)
+    assert plain.scope_ms("router") is None
+    nested = P.reduce(ev, ("mlp", "router"))
+    assert nested.scope_ms("router") == pytest.approx(40e-6)
+    assert nested.scope_ms("mlp") == pytest.approx(20e-6)
+    assert nested.unscoped_ms() == plain.unscoped_ms() == pytest.approx(
+        20e-6)
 
 
 def test_idle_under_put_counts_and_under_loss_wait_does_not():
-    t = P.reduce(_ev(NAMES))
+    t = P.reduce(_ev(NAMES), ALL)
     assert t.steps == 1
     assert t.host_ms() == pytest.approx(12e-6)
     # 0..10 idle under grid and put; 90..100 under the loss wait and
@@ -71,29 +100,30 @@ def test_idle_under_put_counts_and_under_loss_wait_does_not():
 
 
 def test_scopes_and_unscoped_add_up_to_busy():
-    t = P.reduce(_ev(NAMES, devices=2))
+    t = P.reduce(_ev(NAMES, devices=2), ALL)
     assert t.chips == 2
     assert t.scope_ms("attention") == pytest.approx(40e-6)
     assert t.scope_ms("ce") == pytest.approx(20e-6)
     assert t.unscoped_ms() == pytest.approx(20e-6)     # the container is out
     busy = sum(P.T._length(b) for b in t.busy) / t.chips / t.steps / 1e6
-    total = sum(t.scope_ms(s) or 0 for s in P.SCOPES) + t.unscoped_ms()
+    total = sum(t.scope_ms(s) or 0 for s in ALL) + t.unscoped_ms()
     assert total == pytest.approx(busy)
 
 
 def test_missing_names_read_none(monkeypatch):
-    t = P.reduce(_ev(NAMES))
+    t = P.reduce(_ev(NAMES), ALL)
     assert t.scope_ms("mlp") is None and t.scope_ms("ssd") is None
     # a program without scopes or spans (the parent of this benchmark's
     # readers) reads None in every reader, and does not raise
-    bare = P.reduce(_ev([""] * 4))
+    bare = P.reduce(_ev([""] * 4), ALL)
     bare.span_union = {k: v for k, v in bare.span_union.items()
                        if not k.startswith("spmd.")}
-    monkeypatch.setattr(P, "load", lambda: bare)
-    assert {n: _reader(n).read(None, {}) for n in READERS} == dict.fromkeys(
-        READERS)
-    monkeypatch.setattr(P, "load", lambda: t)
-    got = {n: _reader(n).read(None, {}) for n in READERS}
+    facts = {"scopes": ALL}
+    monkeypatch.setattr(P, "load", lambda path=None, scopes=(): bare)
+    assert {n: _reader(n).read(None, facts) for n in READERS} == \
+        dict.fromkeys(READERS)
+    monkeypatch.setattr(P, "load", lambda path=None, scopes=(): t)
+    got = {n: _reader(n).read(None, facts) for n in READERS}
     assert got["attention.ms"] == pytest.approx(40e-6)
     assert got["mlp.ms"] is None and got["engine.host_ms"] is not None
 
@@ -122,9 +152,9 @@ def test_op_names_from_the_traces_hlo_protos(tmp_path):
     with open(path, "rb") as fh:
         modules = P.hlo_op_names(fh.read())
     names = modules["jit_f"]
-    scoped = {P.scope_of(n) for n in names.values()}
+    scoped = {P.scope_of(n, ALL) for n in names.values()}
     assert "attention" in scoped
-    assert any("transpose(" in n and P.scope_of(n) == "attention"
+    assert any("transpose(" in n and P.scope_of(n, ALL) == "attention"
                for n in names.values())
     # the module that ran wins where two share an instruction name
     merged = P.merged_op_names({"other": {"sin.1": "x/mlp/sin"},
@@ -142,15 +172,24 @@ def test_containers_by_their_hlo_text():
                               "fusion(%a), kind=kLoop, calls=%f")
 
 
+def _fixture(name):
+    path = os.path.join(FIXTURE, name)
+    with (lzma.open(path, "rt") if name.endswith(".xz") else open(path)) as f:
+        return json.load(f)
+
+
 def test_recorded_v5e_excerpt():
     """The first 1200 ops of a stablelm-1.6b-l4.seq2k step on a v5e, with
-    the op_names of the trace's HLO proto: the scopes, forward and
-    backward, and the unscoped ops add up to the busy time; the idle time
-    before the first op falls under the transfer; the numbers are those
-    the reduction gave when the fixture was recorded."""
-    with open(os.path.join(FIXTURE, "v5e_stablelm_l4_scopes.json")) as f:
-        fx = json.load(f)
-    t = P.reduce(P.events_from_json(fx))
+    the op_names of the trace's HLO proto, split by that cell's scope set:
+    the scopes, forward and backward, and the unscoped ops add up to the
+    busy time; the idle time before the first op falls under the
+    transfer; the numbers are those the reduction by the five fixed scopes
+    gave when the fixture was recorded, and adding the scope of another
+    cell (``ssd``) moves none of them."""
+    fx = _fixture("v5e_stablelm_l4_scopes.json")
+    scopes = _cell_scopes("stablelm-1.6b-l4.seq2k")
+    assert scopes == ("adam", "attention", "ce", "mlp")
+    t = P.reduce(P.events_from_json(fx), scopes)
     assert t.chips == 1 and t.steps == 1
     assert t.scope_ms("attention") == pytest.approx(25.90046)
     assert t.scope_ms("mlp") == pytest.approx(6.518042)
@@ -158,10 +197,36 @@ def test_recorded_v5e_excerpt():
     assert t.scope_ms("ssd") is None and t.scope_ms("adam") is None
     assert t.unscoped_ms() == pytest.approx(42.477405)
     busy = P.T._length(t.busy[0]) / 1e6
-    total = sum(t.scope_ms(s) or 0 for s in P.SCOPES) + t.unscoped_ms()
+    total = sum(t.scope_ms(s) or 0 for s in scopes) + t.unscoped_ms()
     assert total == pytest.approx(busy, rel=1e-3)
     assert t.host_ms() == pytest.approx(1.896269)
     assert t.exposed_host_ms() == pytest.approx(0.422576)
+    assert P.reduce(P.events_from_json(fx), ALL).scope_ns == t.scope_ns
     backward = [n for n in fx["op_names"].values()
-                if "transpose(" in n and P.scope_of(n) == "attention"]
+                if "transpose(" in n and P.scope_of(n, scopes) == "attention"]
     assert backward
+
+
+def test_recorded_v5e_mamba2_step():
+    """A whole step of mamba2-370m.seq4k on a v5e (``record_fixture.py``),
+    split by that cell's scope set: the numbers are those the reduction by
+    the five fixed scopes gives; no op carries ``attention`` or ``mlp``, so
+    the fixed set and the cell's split alike."""
+    fx = _fixture("v5e_mamba2_370m_step.json.xz")
+    scopes = _cell_scopes("mamba2-370m.seq4k")
+    assert scopes == ("adam", "ce", "ssd")
+    ev = P.events_from_json(fx)
+    t = P.reduce(ev, scopes)
+    assert t.chips == 1 and t.steps == 1
+    assert t.scope_ns == [{"ssd": 623853808, "ce": 66586707,
+                           "adam": 20447724, None: 309531971}]
+    assert t.scope_ms("ssd") == pytest.approx(623.853808)
+    assert t.unscoped_ms() == pytest.approx(309.531971)
+    assert t.scope_ms("attention") is None and t.scope_ms("mlp") is None
+    busy = P.T._length(t.busy[0]) / 1e6
+    assert busy == pytest.approx(1021.303964)
+    total = sum(t.scope_ms(s) for s in scopes) + t.unscoped_ms()
+    assert total == pytest.approx(busy, rel=2e-3)
+    assert t.host_ms() == pytest.approx(1.89732)
+    assert t.exposed_host_ms() == pytest.approx(0.641331)
+    assert P.reduce(ev, ALL).scope_ns == t.scope_ns

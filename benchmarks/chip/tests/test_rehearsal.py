@@ -98,7 +98,7 @@ def test_reference_matches_program_in_float32(cfg):
     from reference import common as C
     from repro.models import model as M
     sp = tiny.spec(cfg)
-    arch = dataclasses.replace(run.program_config(sp["config"]),
+    arch = dataclasses.replace(run.program_config(sp["config"], sp["reference"]),
                                dtype="float32")
     params = run.weights(sp, 9)
     import traffic

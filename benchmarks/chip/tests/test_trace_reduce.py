@@ -74,7 +74,7 @@ def test_reduce_by_hand():
 
 def test_readers_by_hand():
     r = T.reduce(_events(), T.op_classes(HLO))
-    facts = {"flops_per_step": 2e3, "peak_flops_per_s": 1e12}
+    facts = {"flops_per_step": 2e3, "peak_flops_per_s": 1e12, "scopes": ()}
     m = {n: registry.load_module(os.path.join(registry.HERE, "metrics",
                                               n + ".py")).read(r, facts)
          for n in registry.names("metrics")}
