@@ -57,6 +57,8 @@ class ArchConfig:
     logit_softcap: float = 0.0        # gemma2 attn softcap (0 = off)
     final_softcap: float = 0.0        # gemma2 final-logit softcap
     rope_theta: float = 10_000.0
+    rope_fraction: float = 1.0        # share of each head's dims rotated
+    qkv_bias: bool = False            # q/k/v projections carry a bias
     causal: bool = True
 
     # MLP flavour

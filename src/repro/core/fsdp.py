@@ -36,6 +36,13 @@ from repro.core.partition import even_shard_sizes
 
 QUANTUM = 128
 
+#: Named scopes of the unit exchange, for the device trace: the forward
+#: AllGather with its cast, and the backward ReduceScatter of the
+#: gradient with its casts.  (Not ``gather``/``scatter``: those are the
+#: names of lax primitives, the last component of every op's name.)
+GATHER_SCOPE = "unit_gather"
+SCATTER_SCOPE = "unit_scatter"
+
 
 # ---------------------------------------------------------------------------
 # Flat layout of one unit
@@ -146,9 +153,10 @@ def make_mixed_gather(layout: UnitLayout, axis_names, fwd_dtype,
                       bwd_dtype, replica_axes=()):
     """Gather with independent forward/backward precision.
 
-    Forward: AllGather in ``fwd_dtype`` (bf16 halves wire bytes).
-    Backward: ReduceScatter of the cotangent in ``bwd_dtype`` (fp32 keeps
-    the paper's full-precision gradient averaging even with bf16 gathers).
+    Forward: AllGather in ``fwd_dtype`` (bf16 halves wire bytes), under
+    the named scope ``GATHER_SCOPE``.  Backward: ReduceScatter of the
+    cotangent in ``bwd_dtype`` (fp32 keeps the paper's full-precision
+    gradient averaging even with bf16 gathers), under ``SCATTER_SCOPE``.
     The fp32 master shard never leaves the owning rank.
 
     ``replica_axes`` — HSDP mode: state is sharded over ``axis_names``
@@ -158,16 +166,18 @@ def make_mixed_gather(layout: UnitLayout, axis_names, fwd_dtype,
     """
     @jax.custom_vjp
     def gather(shard):
-        return gather_unit(layout, shard.astype(fwd_dtype), axis_names)
+        with jax.named_scope(GATHER_SCOPE):
+            return gather_unit(layout, shard.astype(fwd_dtype), axis_names)
 
     def fwd(shard):
         return gather(shard), None
 
     def bwd(_, ct):
-        g = scatter_grad(layout, ct.astype(bwd_dtype), axis_names)
-        if replica_axes:
-            g = jax.lax.psum(g, replica_axes)
-        return (g.astype(jnp.float32),)
+        with jax.named_scope(SCATTER_SCOPE):
+            g = scatter_grad(layout, ct.astype(bwd_dtype), axis_names)
+            if replica_axes:
+                g = jax.lax.psum(g, replica_axes)
+            return (g.astype(jnp.float32),)
 
     gather.defvjp(fwd, bwd)
     return gather

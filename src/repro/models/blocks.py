@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, AttnKind
 from repro.models.layers.attention import (AttnSpec, attention_apply,
                                            attention_init, decode_attend,
-                                           merge_decode_partials)
+                                           merge_decode_partials, project,
+                                           rotate)
 from repro.models.layers.mlp import mlp_apply, mlp_init
 from repro.models.layers.moe import moe_apply, moe_init
 from repro.models.layers.norms import (layernorm_apply, layernorm_init,
@@ -55,6 +56,8 @@ def attn_spec(cfg: ArchConfig, local: bool) -> AttnSpec:
         softcap=cfg.logit_softcap,
         rope_theta=cfg.rope_theta,
         use_rope=not cfg.learned_pos,
+        rope_fraction=cfg.rope_fraction,
+        qkv_bias=cfg.qkv_bias,
     )
 
 
@@ -113,11 +116,9 @@ def dense_block_apply(params: dict, x: jax.Array, cfg: ArchConfig,
     new_kv = None
     if kv_cache is not None:
         # decode: project q from h, attend over the cache shard
-        from repro.models.layers.rope import apply_rope
         dtype = h.dtype
-        q = jnp.einsum("bsd,dhk->bshk", h, params["attn"]["wq"].astype(dtype))
-        if spec.use_rope:
-            q = apply_rope(q, positions[:, None], spec.rope_theta)
+        q = rotate(project(params["attn"], h, "q", spec), positions[:, None],
+                   spec)
         k_cache, v_cache, kv_pos = kv_cache
         wv, m, l = decode_attend(q, k_cache, v_cache, kv_pos, positions, spec)
         out = merge_decode_partials(wv, m, l, seq_shard_axis)
@@ -143,15 +144,11 @@ def dense_block_apply(params: dict, x: jax.Array, cfg: ArchConfig,
 def decode_project_kv(params: dict, x: jax.Array, cfg: ArchConfig,
                       positions: jax.Array, local: bool = False):
     """Project this token's (k, v) for the cache write (decode mode)."""
-    from repro.models.layers.rope import apply_rope
     spec = attn_spec(cfg, local)
     h = norm_apply(cfg, params["ln_attn"], x)
-    dtype = h.dtype
-    k = jnp.einsum("bsd,dhk->bshk", h, params["attn"]["wk"].astype(dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, params["attn"]["wv"].astype(dtype))
-    if spec.use_rope:
-        k = apply_rope(k, positions[:, None], spec.rope_theta)
-    return k, v
+    k = project(params["attn"], h, "k", spec)
+    v = project(params["attn"], h, "v", spec)
+    return rotate(k, positions[:, None], spec), v
 
 
 # ---------------------------------------------------------------------------

@@ -39,11 +39,17 @@ class AttnSpec:
     softcap: float = 0.0
     rope_theta: float = 10_000.0
     use_rope: bool = True      # encoders use learned/absolute positions
+    rope_fraction: float = 1.0  # share of each head's dims rotated
+    qkv_bias: bool = False     # q/k/v projections carry a bias
     query_scale: float = 0.0   # 0 → 1/sqrt(head_dim)
 
     @property
     def scale(self) -> float:
         return self.query_scale or self.head_dim ** -0.5
+
+    @property
+    def rotary_dims(self) -> int:
+        return int(self.rope_fraction * self.head_dim)
 
     @property
     def q_per_kv(self) -> int:
@@ -53,13 +59,37 @@ class AttnSpec:
 def attention_init(key: jax.Array, d_model: int, spec: AttnSpec) -> dict:
     kq, kk, kv, ko = jax.random.split(key, 4)
     hd = spec.head_dim
-    return {
+    p = {
         "wq": dense_init(kq, (d_model, spec.n_heads, hd), fan_in=d_model),
         "wk": dense_init(kk, (d_model, spec.n_kv_heads, hd), fan_in=d_model),
         "wv": dense_init(kv, (d_model, spec.n_kv_heads, hd), fan_in=d_model),
         "wo": dense_init(ko, (spec.n_heads, hd, d_model),
                          fan_in=spec.n_heads * hd),
     }
+    if spec.qkv_bias:
+        p["bq"] = jnp.zeros((spec.n_heads, hd), jnp.float32)
+        p["bk"] = jnp.zeros((spec.n_kv_heads, hd), jnp.float32)
+        p["bv"] = jnp.zeros((spec.n_kv_heads, hd), jnp.float32)
+    return p
+
+
+def project(params: dict, x: jax.Array, name: str,
+            spec: AttnSpec) -> jax.Array:
+    """``x`` (B, S, D) through the ``name`` ("q", "k" or "v") projection
+    to (B, S, heads, hd), plus its bias where the spec has one."""
+    dtype = x.dtype
+    y = jnp.einsum("bsd,dhk->bshk", x, params["w" + name].astype(dtype))
+    if spec.qkv_bias:
+        y = y + params["b" + name].astype(dtype)
+    return y
+
+
+def rotate(x: jax.Array, positions: jax.Array, spec: AttnSpec) -> jax.Array:
+    """Rotary embedding of q or k by ``positions`` (B, S), over the
+    spec's rotary dims; identity where the spec uses no rotary."""
+    if not spec.use_rope:
+        return x
+    return apply_rope(x, positions, spec.rope_theta, spec.rotary_dims)
 
 
 def _softcap(logits: jax.Array, cap: float) -> jax.Array:
@@ -276,14 +306,11 @@ def attention_apply(params: dict, x: jax.Array, spec: AttnSpec,
     (decode).  ``return_kv`` also returns the fresh (k, v) for cache fills.
     """
     dtype = x.dtype
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
-    if spec.use_rope:
-        q = apply_rope(q, positions, spec.rope_theta)
+    q = rotate(project(params, x, "q", spec), positions, spec)
     if kv_override is None:
-        k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dtype))
-        if spec.use_rope:
-            k = apply_rope(k, positions, spec.rope_theta)
+        k = project(params, x, "k", spec)
+        v = project(params, x, "v", spec)
+        k = rotate(k, positions, spec)
         kv_positions = positions
     else:
         k, v, kv_positions = kv_override

@@ -13,8 +13,9 @@ import pytest
 
 
 @pytest.mark.integration
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "mamba2-370m"],
-                         ids=["dense", "ssm"])
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "mamba2-370m",
+                                  "stablelm-2-1.6b"],
+                         ids=["dense", "ssm", "stablelm2"])
 def test_spmd_step_matches_reference(subproc, arch):
     out = subproc(f"ARCH = {arch!r}\n" + """
 import jax, jax.numpy as jnp, numpy as np
